@@ -22,6 +22,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.paths import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="longer training / more tokens")

@@ -21,6 +21,18 @@ RL303 pallas-missing-guard    a ``pl.pallas_call`` on packed planes whose
                               enclosing function neither asserts
                               ``bk % PACK_BLOCK`` nor routes tiles
                               through ``clamp_tiles``/``_tile_sizes``.
+RL304 block-one-row           a 2-D block shape written ``(1, x)``:
+                              Mosaic needs a block's last two dims to
+                              divide by (8, 128) or span the array, and a
+                              one-row block of a per-expert ``(E, C)``
+                              operand does neither (the TPU compiler
+                              refused ``(1, bm)`` and ``(1, 1)`` blocks of
+                              the fused kernel).  Give the operand a
+                              trailing unit dim (``(E, C, 1)`` blocked
+                              ``(1, bm, 1)``) or pass it by scalar
+                              prefetch.  Checked on ``BlockSpec`` calls
+                              and on calls of local helpers that build
+                              one from their first argument.
 
 ``PACK_BLOCK`` is read from the ``core/quantize.py`` AST (so the lint
 needs no jax import to parse); the VMEM formula is imported from
@@ -183,9 +195,44 @@ def rl303(scope, ctx) -> List[Finding]:
     return out
 
 
+@rule("RL304", "one-row 2-D Pallas block shape")
+def rl304(scope, ctx) -> List[Finding]:
+    out = []
+    for module, tree in ctx.index.trees.items():
+        path = ctx.index.module_paths[module]
+        makers = {"BlockSpec"} | {
+            f.name for f in ast.walk(tree)
+            if isinstance(f, (ast.FunctionDef, ast.AsyncFunctionDef))
+            and any(_is_blockspec(n) for n in ast.walk(f))}
+        for call in ast.walk(tree):
+            if not isinstance(call, ast.Call) or not call.args:
+                continue
+            head = (_dotted(call.func) or "").split(".")[-1]
+            shape = call.args[0]
+            if head in makers and _one_row_2d(shape):
+                out.append(ctx.finding_at(
+                    "RL304", path, call,
+                    f"block shape {ast.unparse(shape)} is one row of a 2-D "
+                    f"operand: Mosaic needs the last two block dims to "
+                    f"divide by (8, 128) or span the array; add a trailing "
+                    f"unit dim or use scalar prefetch"))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
+
+def _is_blockspec(node: ast.AST) -> bool:
+    return isinstance(node, ast.Call) and \
+        (_dotted(node.func) or "").endswith("BlockSpec")
+
+
+def _one_row_2d(shape: ast.AST) -> bool:
+    return (isinstance(shape, ast.Tuple) and len(shape.elts) == 2
+            and isinstance(shape.elts[0], ast.Constant)
+            and shape.elts[0].value == 1)
+
 
 def _kernel_defaults(ctx):
     """(path, FunctionDef, {kw: default int}) for functions that launch

@@ -119,6 +119,28 @@ def whiten_vector(moment: np.ndarray, eps: float = 1e-6) -> np.ndarray:
     return np.sqrt(m + eps)
 
 
+def _host_svd(a: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Thin SVD by the host's LAPACK (``gesdd``), returned as
+    uncommitted arrays on the default device, as ``jnp.linalg.svd``
+    would return them (committed outputs would give the params another
+    jit signature and the decode loop a second compile).
+
+    XLA's TPU SVD of one expert residual at Mixtral width (4096 x 14336)
+    compiles into a 400 MB program and ran a one-chip host out of its
+    40 GiB; the host takes about 15 s.  One routine on every platform
+    also keeps the compensators independent of the accelerator.  A wide
+    matrix is factored through its transpose, which LAPACK does about a
+    third faster."""
+    import scipy.linalg
+    h = np.asarray(a, np.float32)
+    wide = h.shape[0] < h.shape[1]
+    uu, ss, vt = scipy.linalg.svd(h.T if wide else h, full_matrices=False,
+                                  lapack_driver="gesdd", check_finite=False)
+    if wide:
+        uu, vt = vt.T, uu.T
+    return jnp.asarray(uu), jnp.asarray(ss), jnp.asarray(vt)
+
+
 def whitened_residual_factors(resid: jax.Array, rank: int, pad_rank: int,
                               moment: Optional[np.ndarray] = None,
                               eps: float = 1e-6
@@ -141,7 +163,7 @@ def whitened_residual_factors(resid: jax.Array, rank: int, pad_rank: int,
     else:
         white = jnp.asarray(whiten_vector(moment, eps), jnp.float32)
         r_in = resid * white[:, None]
-    uu, ss, vt = jnp.linalg.svd(r_in, full_matrices=False)
+    uu, ss, vt = _host_svd(r_in)
     sq = jnp.sqrt(ss[:pad_rank])
     uu = uu[:, :pad_rank] * sq[None, :]
     vv = vt[:pad_rank, :] * sq[:, None]
@@ -167,16 +189,23 @@ def compress_expert_stack(w: jax.Array, qcfg: QuantConfig,
 
     Returns the packed artifact plus a report dict (kurtosis, ranks,
     bits, residual norms before/after compensation) used by benchmarks.
+
+    Work runs one expert at a time: no (E, K, N) float32 temporary is
+    ever formed, so a stack whose dense form barely fits the device
+    still compresses.
     """
     E, K, N = w.shape
-    w32 = jnp.asarray(w, jnp.float32)
+
+    def w32(e):
+        return jnp.asarray(w[e], jnp.float32)
+
     # group_size <= 0 means per-channel (one group spanning all of K) —
     # the coarse granularity at which RTN/GPTQ-class int2 collapses
     if qcfg.group_size <= 0 or qcfg.group_size > K:
         qcfg = dataclasses.replace(qcfg, group_size=K)
 
     # 1. per-expert kurtosis (paper §3.1 step 1)
-    kurt = np.array([float(kurtosis(w32[e])) for e in range(E)])
+    kurt = np.array([float(kurtosis(w32(e))) for e in range(E)])
 
     # 2. HQQ quantization (paper §3.1 step 2; done before allocation so the
     # 'error' strategy can rank by measured residuals).  Heterogeneous
@@ -195,7 +224,7 @@ def compress_expert_stack(w: jax.Array, qcfg: QuantConfig,
         return quantize_with_params(we, s, z, b, qcfg.group_size,
                                     store_bits=store_bits)
 
-    qts = [_q(w32[e], int(expert_bits[e])) for e in range(E)]
+    qts = [_q(w32(e), int(expert_bits[e])) for e in range(E)]
 
     # 3. rank allocation: kurtosis proxy (paper) | measured residual
     # (beyond-paper) | uniform (ablation)
@@ -204,7 +233,7 @@ def compress_expert_stack(w: jax.Array, qcfg: QuantConfig,
     if ranks is None:
         if strategy == "error":
             from .quantize import quant_error
-            errs = np.array([float(quant_error(w32[e], qts[e]))
+            errs = np.array([float(quant_error(w32(e), qts[e]))
                              for e in range(E)])
             ranks = allocate_ranks(errs, qcfg.rank_budget, qcfg.rank_buckets,
                                    max_rank=max_rank)
@@ -223,23 +252,34 @@ def compress_expert_stack(w: jax.Array, qcfg: QuantConfig,
     zero = jnp.stack([qt.zero for qt in qts])
 
     # 4. residual SVD at the allocated rank (activation-whitened when
-    # calibrated moments are given), zero-padded to pad_rank
-    deq = jnp.stack([dequantize(qt) for qt in qts])
-    resid = w32 - deq
+    # calibrated moments are given), zero-padded to pad_rank; a rank-0
+    # expert's factors are exact zeros and need no SVD
     us, vs, uss, vss = [], [], [], []
+    err_quant, err_comp = [], []
     for e in range(E):
         r = int(ranks[e])
-        uu, vv = whitened_residual_factors(
-            resid[e], r, pad_rank,
-            moment=None if moments is None else moments[e])
+        we = w32(e)
+        resid = we - dequantize(qts[e])
+        if r > 0:
+            uu, vv = whitened_residual_factors(
+                resid, r, pad_rank,
+                moment=None if moments is None else moments[e])
+        else:
+            uu = jnp.zeros((K, pad_rank), jnp.float32)
+            vv = jnp.zeros((pad_rank, N), jnp.float32)
         if qcfg.factor_bits >= 16:
-            us.append(uu.astype(jnp.bfloat16)); vs.append(vv.astype(jnp.bfloat16))
-            uss.append(jnp.ones((1, pad_rank), jnp.float32))
-            vss.append(jnp.ones((pad_rank, 1), jnp.float32))
+            qu, qv = uu.astype(jnp.bfloat16), vv.astype(jnp.bfloat16)
+            su = jnp.ones((1, pad_rank), jnp.float32)
+            sv = jnp.ones((pad_rank, 1), jnp.float32)
         else:
             qu, su = _sym_quant_cols(uu, qcfg.factor_bits, axis=0)
             qv, sv = _sym_quant_cols(vv, qcfg.factor_bits, axis=1)
-            us.append(qu); vs.append(qv); uss.append(su); vss.append(sv)
+        us.append(qu); vs.append(qv); uss.append(su); vss.append(sv)
+        # 5. report: residual norms before/after compensation
+        comp = (qu.astype(jnp.float32) * su) @ (qv.astype(jnp.float32) * sv)
+        nw = jnp.maximum(jnp.linalg.norm(we), 1e-12)
+        err_quant.append(float(jnp.linalg.norm(resid) / nw))
+        err_comp.append(float(jnp.linalg.norm(resid - comp) / nw))
 
     hetero = bool((expert_bits != expert_bits[0]).any()) \
         or int(expert_bits[0]) != store_bits
@@ -251,18 +291,12 @@ def compress_expert_stack(w: jax.Array, qcfg: QuantConfig,
         ranks=tuple(int(r) for r in ranks), pad_rank=pad_rank,
         factor_bits=qcfg.factor_bits,
         expert_bits=tuple(int(b) for b in expert_bits) if hetero else None)
-
-    # 5. report
-    comp = stack.compensation_all()
-    nw = jnp.maximum(jnp.linalg.norm(w32.reshape(E, -1), axis=1), 1e-12)
     report = {
         "kurtosis": kurt,
         "ranks": np.asarray(ranks),
         "bits": np.asarray(expert_bits),
-        "rel_err_quant": np.asarray(
-            jnp.linalg.norm(resid.reshape(E, -1), axis=1) / nw),
-        "rel_err_comp": np.asarray(
-            jnp.linalg.norm((resid - comp).reshape(E, -1), axis=1) / nw),
+        "rel_err_quant": np.asarray(err_quant),
+        "rel_err_comp": np.asarray(err_comp),
     }
     return stack, report
 

@@ -14,9 +14,9 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import axis_size, shard_map
 
 
 def _quantize_grad(g: jax.Array, key) -> tuple[jax.Array, jax.Array]:

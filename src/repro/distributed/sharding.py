@@ -15,9 +15,9 @@ from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-export for EP/collectives)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map  # noqa: F401  (re-export for EP/collectives)
 from ..config import ParallelConfig
 
 # (path-suffix regex, logical axes aligned to the TRAILING dims)

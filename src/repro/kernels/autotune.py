@@ -3,9 +3,10 @@
 Three-level lookup, cheapest first:
 
 1. in-memory cache (one entry per problem key per process);
-2. the persisted per-device cache ``~/.cache/repro/autotune.json``
+2. the persisted per-device cache ``<checkout>/.autotune.json``
    (override with ``REPRO_AUTOTUNE_CACHE``), written only by an actual
-   on-device timing sweep;
+   on-device timing sweep and read only when ``REPRO_AUTOTUNE`` asks for
+   tuning, so a default run depends on tracked files alone;
 3. the deterministic in-repo ``DEFAULT_TABLE`` seeded from the roofline
    tile menus (``launch/roofline.py::fused_tile_candidates``) — CI and
    fresh checkouts never tune, they look up.
@@ -26,6 +27,7 @@ from __future__ import annotations
 import json
 import os
 import time
+import warnings
 from pathlib import Path
 from typing import Dict, Optional, Tuple
 
@@ -57,18 +59,18 @@ def _m_bucket(m: int) -> int:
 
 
 def device_kind() -> str:
+    """The local device kind (the persisted table's top-level key);
+    raises where JAX cannot name a device."""
     import jax
-    try:
-        return jax.devices()[0].device_kind.replace(" ", "_")
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind.replace(" ", "_")
 
 
 def cache_path() -> Path:
     env = os.environ.get(_CACHE_ENV)
     if env:
         return Path(env)
-    return Path.home() / ".cache" / "repro" / "autotune.json"
+    from ..paths import CHECKOUT
+    return CHECKOUT / ".autotune.json"
 
 
 def _key_str(kind: str, bits: int, group_size: int, rank: int,
@@ -132,7 +134,8 @@ class Autotuner:
         key = _key_str(kind, bits, group_size, rank, m, k, n)
         if key in self._mem:
             return self._mem[key]
-        disk = self._load_disk().get(device_kind(), {})
+        disk = (self._load_disk().get(device_kind(), {})
+                if autotune_enabled() else {})
         hit = disk.get(key) if isinstance(disk, dict) else None
         try:
             tiles = tuple(hit["tiles"]) if hit else None
@@ -209,6 +212,7 @@ def tune_fused(xe, stack, me, ge, rank_cap, *, out_dtype, interpret: bool,
         cands = [clamp_tiles(m, k, n, 8, 256, 512,
                              group_size=stack.group_size)]
     best, best_us = None, float("inf")
+    failed = []
     for bm, bn, bk in cands:
         bm, bn, bk = clamp_tiles(m, k, n, bm, bn, bk,
                                  group_size=stack.group_size)
@@ -224,14 +228,16 @@ def tune_fused(xe, stack, me, ge, rank_cap, *, out_dtype, interpret: bool,
             for _ in range(repeats):
                 run()
             us = (time.perf_counter() - t0) / repeats * 1e6
-        except Exception:
+        except Exception as exc:      # a candidate the compiler refuses
+            failed.append(((bm, bn, bk), f"{type(exc).__name__}: {exc}"))
+            warnings.warn(f"autotune: fused tiles {(bm, bn, bk)} failed "
+                          f"for b{stack.bits} k{k} n{n}: {failed[-1][1]}")
             continue
         if us < best_us:
             best, best_us = (bm, bn, bk), us
     if best is None:
-        best = clamp_tiles(m, k, n, 8, 256, 512,
-                           group_size=stack.group_size)
-        best_us = 0.0
+        raise RuntimeError(f"autotune: every fused tile candidate failed "
+                           f"for b{stack.bits} k{k} n{n}: {failed}")
     _TUNER.record("fused", best, best_us, bits=stack.bits,
                   group_size=stack.group_size, rank=rank, m=m, k=k, n=n,
                   persist=not interpret)

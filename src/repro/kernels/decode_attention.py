@@ -11,6 +11,12 @@ bytes (~1.06 B/elem incl. scales vs 2 for bf16).
 Grid: (B, KVH, S/bs); GQA handled by evaluating all G = H/KVH query heads
 of the kv-head per block.  Ring caches pass ``kv_pos`` (-1 = empty slot)
 and masking is pure position arithmetic — no sorting after wraparound.
+
+Blocks are Mosaic-legal: K/V enter as ``(B, S, KVH * hd)`` (a free
+reshape) blocked ``(bs, hd)`` at lane block ``kv-head``, and positions
+and scales enter with a unit dim before the sequence, blocked
+``(1, bs)``; so on a TPU ``hd`` divides by 128 and ``bs`` divides by 128
+or spans the cache.
 """
 from __future__ import annotations
 
@@ -22,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import PallasCompilerParams
 
 NEG = -2.0 ** 30
 
@@ -38,24 +43,25 @@ def _kernel(n_s, bs, window, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)                 # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bs, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)        # (bs, hd)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (G, bs)
+    k = k_ref[...].astype(jnp.float32)               # (bs, hd)
+    v = v_ref[...].astype(jnp.float32)               # (bs, hd)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (G, bs)
     if ks_ref is not None:
-        s = s * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
-    pos = pos_ref[0]                                 # (bs,)
-    cur = cur_ref[0, 0]
+        s = s * ks_ref[...].astype(jnp.float32)      # (1, bs)
+    pos = pos_ref[...]                               # (1, bs)
+    cur = cur_ref[...]                               # (1, 1)
     valid = (pos >= 0) & (pos <= cur)
     if window is not None and window > 0:
         valid &= pos > cur - window
-    s = jnp.where(valid[None, :], s, NEG)
+    s = jnp.where(valid, s, NEG)
 
     m_prev = m_ref[...]                              # (G, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)                           # (G, bs)
     if vs_ref is not None:
-        pv = p * vs_ref[0, :, 0].astype(jnp.float32)[None, :]
+        pv = p * vs_ref[...].astype(jnp.float32)
     else:
         pv = p
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
@@ -90,18 +96,22 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     grid = (b, kvh, n_s)
     in_specs = [
         pl.BlockSpec((1, g, hd), lambda bb, kk, ss: (bb * kvh + kk, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda bb, kk, ss: (bb, ss, kk, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda bb, kk, ss: (bb, ss, kk, 0)),
+        pl.BlockSpec((None, bs, hd), lambda bb, kk, ss: (bb, ss, kk)),
+        pl.BlockSpec((None, bs, hd), lambda bb, kk, ss: (bb, ss, kk)),
     ]
-    args = [qg.reshape(b * kvh, g, hd), k, v]
+    args = [qg.reshape(b * kvh, g, hd), k.reshape(b, s_len, kvh * hd),
+            v.reshape(b, s_len, kvh * hd)]
     use_scales = k_scale is not None
     if use_scales:
-        in_specs += [pl.BlockSpec((1, bs, 1), lambda bb, kk, ss: (bb, ss, kk)),
-                     pl.BlockSpec((1, bs, 1), lambda bb, kk, ss: (bb, ss, kk))]
-        args += [k_scale, v_scale]
-    in_specs += [pl.BlockSpec((1, bs), lambda bb, kk, ss: (bb, ss)),
-                 pl.BlockSpec((1, 1), lambda bb, kk, ss: (bb, 0))]
-    args += [kv_pos, cur_pos[:, None]]
+        # (B, S, KVH) -> (B, KVH, 1, S): a row of scales per kv-head
+        scale_spec = pl.BlockSpec((None, None, 1, bs),
+                                  lambda bb, kk, ss: (bb, kk, 0, ss))
+        in_specs += [scale_spec, scale_spec]
+        args += [jnp.transpose(k_scale, (0, 2, 1))[:, :, None, :],
+                 jnp.transpose(v_scale, (0, 2, 1))[:, :, None, :]]
+    in_specs += [pl.BlockSpec((None, 1, bs), lambda bb, kk, ss: (bb, 0, ss)),
+                 pl.BlockSpec((None, 1, 1), lambda bb, kk, ss: (bb, 0, 0))]
+    args += [kv_pos[:, None, :], cur_pos[:, None, None]]
 
     kernel = functools.partial(
         _kernel, n_s, bs, window) if use_scales else functools.partial(
@@ -116,7 +126,7 @@ def flash_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, 1), jnp.float32),
                         pltpu.VMEM((g, hd), jnp.float32)],
-        compiler_params=PallasCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode" + ("_kv8" if use_scales else ""),

@@ -216,8 +216,8 @@ def fused_expert_matmul(xe: jax.Array, stack, me: jax.Array, *,
     mep = jnp.pad(me, ((0, 0), (0, pc))) if pc else me
     gep = (jnp.pad(gates, ((0, 0), (0, pc)))
            if gates is not None and pc else gates)
-    cap = jnp.full((1, 1), r, jnp.int32) if rank_cap is None else \
-        jnp.asarray(rank_cap, jnp.int32).reshape(1, 1)
+    cap = jnp.full((1,), r, jnp.int32) if rank_cap is None else \
+        jnp.asarray(rank_cap, jnp.int32).reshape(1)
     # TRUE per-expert widths; inside shard_map regions the runtime leaves
     # carry a local expert slice while ``expert_bits`` (static metadata)
     # stays global — fall back to the container width there (bit-exact:
@@ -226,10 +226,11 @@ def fused_expert_matmul(xe: jax.Array, stack, me: jax.Array, *,
     if ebs is not None and len(ebs) != e:
         ebs = None
     eb = jnp.asarray(ebs if ebs is not None else (stack.bits,) * e,
-                     jnp.int32).reshape(e, 1)
+                     jnp.int32)
     ye = fused_expert_matmul_pallas(
         xep, stack.planes, stack.scale, stack.zero, stack.u, stack.u_scale,
-        stack.v, stack.v_scale, mep, gep, cap, eb,
+        stack.v, stack.v_scale, mep[..., None],
+        None if gep is None else gep[..., None], cap, eb,
         bits=stack.bits, group_size=stack.group_size, bm=bm, bn=bn, bk=bk,
         out_dtype=out_dtype, interpret=(impl == "pallas_interpret"))
     return ye[:, :c] if pc else ye

@@ -25,21 +25,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import PallasCompilerParams
-
 from ..core.quantize import PACK_BLOCK, PLANES
 
 
-def _unpack_tile(plane_vals, bits: int, bk: int, bn: int) -> jax.Array:
-    """Unpack loaded plane tiles -> (bk, bn) uint8 codes (VMEM, vectorized)."""
+def _unpack_tile(plane_vals, bits: int, bk: int, bn: int,
+                 width=None) -> jax.Array:
+    """Unpack loaded plane tiles -> (bk, bn) int32 codes (VMEM, vectorized).
+
+    Bytes are widened to int32 before any shift or mask: Mosaic has no
+    sub-word shifts and no uint8 -> float cast.  ``width`` (traced
+    scalar, optional) is the expert's TRUE bit width: planes whose bit
+    offset lies at or above it carry no information in a wider shared
+    container and are masked out.
+    """
     out = None
     for (p, off), pk in zip(PLANES[bits], plane_vals):
         c = 8 // p
-        mask = jnp.uint8((1 << p) - 1)
-        blocks = pk.reshape(bk // PACK_BLOCK, PACK_BLOCK // c, bn)
+        mask = (1 << p) - 1
+        blocks = pk.astype(jnp.int32).reshape(bk // PACK_BLOCK,
+                                              PACK_BLOCK // c, bn)
         chunks = [(blocks >> (j * p)) & mask for j in range(c)]
-        sub = jnp.stack(chunks, axis=1).reshape(bk, bn)
-        sub = (sub << off).astype(jnp.uint8)
+        sub = jnp.stack(chunks, axis=1).reshape(bk, bn) << off
+        if width is not None:
+            sub = jnp.where(width > off, sub, 0)
         out = sub if out is None else out | sub
     return out
 
@@ -115,7 +123,7 @@ def _pallas_qmm(x, planes, scale, zero, xu, v, *, bits, group_size,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=PallasCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"quant_matmul_b{bits}" + ("_lowrank" if fuse else ""),
@@ -160,14 +168,16 @@ def lowrank_comp_matmul_pallas(x: jax.Array, planes: Tuple[jax.Array, ...],
 # fused expert-stack decode kernel
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(bits, group_size, n_k, bm, bn, bk, pad_rank, has_gates,
-                  x_ref, *refs):
+def _fused_kernel(bits, group_size, n_k, pad_rank, has_gates,
+                  cap_ref, eb_ref, x_ref, *refs):
     """One grid step of the fused decode kernel (see fused_expert_matmul).
 
     Grid (e, i, j, kk): expert e, token tile i, output tile j, K step kk
-    (innermost, sequential).  refs layout:
-      [planes..., scale, zero, u, u_scale, v, v_scale, me, (ge,)
-       rank_cap, expert_bits] + [out] + [acc, xu_acc scratch]
+    (innermost, sequential).  ``cap_ref`` (1,) / ``eb_ref`` (E,) are the
+    scalar-prefetched rank cap and TRUE per-expert widths (SMEM).  refs
+    layout:
+      [planes..., scale, zero, u, u_scale, v, v_scale, me, (ge,)]
+      + [out] + [acc, xu_acc scratch]
     Everything accumulates in f32 VMEM scratch; only the finished
     (bm, bn) gate-weighted tile is ever written back to HBM.
     """
@@ -181,9 +191,9 @@ def _fused_kernel(bits, group_size, n_k, bm, bn, bk, pad_rank, has_gates,
     if has_gates:
         ge_ref = refs[pos]
         pos += 1
-    cap_ref, eb_ref = refs[pos], refs[pos + 1]
-    out_ref, acc_ref, xu_ref = refs[pos + 2], refs[pos + 3], refs[pos + 4]
+    out_ref, acc_ref, xu_ref = refs[pos], refs[pos + 1], refs[pos + 2]
 
+    e = pl.program_id(0)
     kk = pl.program_id(3)
 
     @pl.when(kk == 0)
@@ -191,32 +201,22 @@ def _fused_kernel(bits, group_size, n_k, bm, bn, bk, pad_rank, has_gates,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         xu_ref[...] = jnp.zeros_like(xu_ref)
 
-    # -- dequant at this expert's TRUE width: planes whose bit offset lies
-    # at or above expert_bits[e] carry no information (hetero stacks store
-    # sub-width codes in a shared container) and are masked out of the
-    # unpack, so the true width is first-class in the kernel rather than
-    # silently widened to the container.
-    eb = eb_ref[0, 0]
-    codes = None
-    for (p, off), pk in zip(PLANES[bits], [r[...] for r in planes]):
-        c = 8 // p
-        mask = jnp.uint8((1 << p) - 1)
-        blocks = pk.reshape(1, bk // PACK_BLOCK, PACK_BLOCK // c, bn)
-        chunks = [(blocks >> (j * p)) & mask for j in range(c)]
-        sub = jnp.stack(chunks, axis=2).reshape(bk, bn)
-        sub = jnp.where(eb > off, (sub << off).astype(jnp.uint8),
-                        jnp.uint8(0))
-        codes = sub if codes is None else codes | sub
-    w = _dequant_tile(codes, scale_ref[0], zero_ref[0], group_size, bk, bn)
-
+    # -- dequant at this expert's TRUE width: planes at or above
+    # expert_bits[e] are masked out of the unpack (hetero stacks store
+    # sub-width codes in a shared container), so the true width is
+    # first-class in the kernel rather than silently widened
     x = x_ref[0].astype(jnp.float32)                       # (bm, bk)
+    bk, bn = x.shape[1], out_ref.shape[2]
+    codes = _unpack_tile([r[0] for r in planes], bits, bk, bn,
+                         width=eb_ref[e])
+    w = _dequant_tile(codes, scale_ref[0], zero_ref[0], group_size, bk, bn)
     acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     # -- rank-space compensator activation: (x * me) @ (U * u_scale),
     # accumulated over K alongside the main matmul (j-invariant; cheap
     # rank-R duplicate work per j tile beats an HBM round-trip for xu)
-    xm = x * me_ref[0][:, None].astype(jnp.float32)
-    ud = u_ref[0].astype(jnp.float32) * us_ref[0, 0]       # (bk, R)
+    xm = x * me_ref[0]                                     # (bm, 1) mask
+    ud = u_ref[0].astype(jnp.float32) * us_ref[0]          # (bk, R)
     xu_ref[...] += jnp.dot(xm, ud, preferred_element_type=jnp.float32)
 
     @pl.when(kk == n_k - 1)
@@ -225,14 +225,14 @@ def _fused_kernel(bits, group_size, n_k, bm, bn, bk, pad_rank, has_gates,
         # traced rank cap: 0/1 mask over the padded rank dim (a plan-row
         # change is data, never a recompile)
         rmask = (jax.lax.broadcasted_iota(jnp.int32, (1, pad_rank), 1)
-                 < cap_ref[0, 0]).astype(jnp.float32)
-        xu = xu_ref[...] * rmask * vs_ref[0, :, 0][None, :]
-        vd = v_ref[0].astype(jnp.float32)                  # (R, bn)
-        acc = acc + jnp.dot(xu, vd, preferred_element_type=jnp.float32)
+                 < cap_ref[0]).astype(jnp.float32)
+        vd = v_ref[0].astype(jnp.float32) * vs_ref[0]      # (R, bn)
+        acc = acc + jnp.dot(xu_ref[...] * rmask, vd,
+                            preferred_element_type=jnp.float32)
         if has_gates:
             # top-n combine epilogue: fold the router gate in-kernel so
             # the (E, C, N) buffer leaves as ready-to-scatter partials
-            acc = acc * ge_ref[0][:, None].astype(jnp.float32)
+            acc = acc * ge_ref[0]
         out_ref[0] = acc.astype(out_ref.dtype)
 
 
@@ -259,9 +259,11 @@ def fused_expert_matmul_pallas(xe: jax.Array, planes: Tuple[jax.Array, ...],
     xe: (E, C, K) dispatched tokens;  planes[i]: (E, K//c_i, N)
     scale/zero: (E, K//G, N);  u: (E, K, R);  v: (E, R, N)
     u_scale: (E, 1, R);  v_scale: (E, R, 1)
-    me: (E, C) top-n compensation mask;  ge: (E, C) router gates (None =
-    unweighted);  rank_cap: (1, 1) i32 traced plan value;
-    expert_bits: (E, 1) i32 TRUE per-expert widths.
+    me: (E, C, 1) top-n compensation mask;  ge: (E, C, 1) router gates
+    (None = unweighted) -- the trailing unit dim keeps the blocks
+    Mosaic-legal (a block's last two dims tile by (8, 128) or span the
+    array);  rank_cap: (1,) i32 traced plan value;  expert_bits: (E,) i32
+    TRUE per-expert widths.  The last two ride scalar prefetch (SMEM).
 
     The f32 accumulator and the (bm, R) rank-space activation live in
     VMEM scratch for the whole K walk — no intermediate (dequantized
@@ -276,38 +278,42 @@ def fused_expert_matmul_pallas(xe: jax.Array, planes: Tuple[jax.Array, ...],
     n_k = k // bk
     has_gates = ge is not None
 
-    in_specs = [pl.BlockSpec((1, bm, bk), lambda e, i, j, kk: (e, i, kk))]
-    in_specs += [pl.BlockSpec((1, bk // (8 // p), bn),
-                              lambda e, i, j, kk: (e, kk, j))
+    # index maps take the grid indices, then the scalar-prefetch refs
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda e, i, j, kk, cap, eb:
+                            index(e, i, j, kk))
+
+    in_specs = [spec((1, bm, bk), lambda e, i, j, kk: (e, i, kk))]
+    in_specs += [spec((1, bk // (8 // p), bn),
+                      lambda e, i, j, kk: (e, kk, j))
                  for p, _ in PLANES[bits]]
-    in_specs += [pl.BlockSpec((1, bk // group_size, bn),
-                              lambda e, i, j, kk: (e, kk, j))] * 2
-    in_specs += [pl.BlockSpec((1, bk, r), lambda e, i, j, kk: (e, kk, 0)),
-                 pl.BlockSpec((1, 1, r), lambda e, i, j, kk: (e, 0, 0)),
-                 pl.BlockSpec((1, r, bn), lambda e, i, j, kk: (e, 0, j)),
-                 pl.BlockSpec((1, r, 1), lambda e, i, j, kk: (e, 0, 0)),
-                 pl.BlockSpec((1, bm), lambda e, i, j, kk: (e, i))]
+    in_specs += [spec((1, bk // group_size, bn),
+                      lambda e, i, j, kk: (e, kk, j))] * 2
+    in_specs += [spec((1, bk, r), lambda e, i, j, kk: (e, kk, 0)),
+                 spec((1, 1, r), lambda e, i, j, kk: (e, 0, 0)),
+                 spec((1, r, bn), lambda e, i, j, kk: (e, 0, j)),
+                 spec((1, r, 1), lambda e, i, j, kk: (e, 0, 0)),
+                 spec((1, bm, 1), lambda e, i, j, kk: (e, i, 0))]
     args = [xe, *planes, scale, zero, u, u_scale, v, v_scale, me]
     if has_gates:
-        in_specs += [pl.BlockSpec((1, bm), lambda e, i, j, kk: (e, i))]
+        in_specs += [spec((1, bm, 1), lambda e, i, j, kk: (e, i, 0))]
         args += [ge]
-    in_specs += [pl.BlockSpec((1, 1), lambda e, i, j, kk: (0, 0)),
-                 pl.BlockSpec((1, 1), lambda e, i, j, kk: (e, 0))]
-    args += [rank_cap, expert_bits]
 
-    kernel = functools.partial(_fused_kernel, bits, group_size, n_k,
-                               bm, bn, bk, r, has_gates)
+    kernel = functools.partial(_fused_kernel, bits, group_size, n_k, r,
+                               has_gates)
     return pl.pallas_call(
         kernel,
-        grid=(e, m // bm, n // bn, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, kk: (e, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(e, m // bm, n // bn, n_k),
+            in_specs=in_specs,
+            out_specs=spec((1, bm, bn), lambda e, i, j, kk: (e, i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                            pltpu.VMEM((bm, r), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
-                        pltpu.VMEM((bm, r), jnp.float32)],
-        compiler_params=PallasCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name=f"fused_expert_b{bits}" + ("_gated" if has_gates else ""),
-    )(*args)
+    )(rank_cap, expert_bits, *args)

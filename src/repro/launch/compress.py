@@ -25,8 +25,11 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from ..paths import enable_compile_cache
+
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="offline calibration + heterogeneous precision "
                     "allocation -> serialized compression artifact")

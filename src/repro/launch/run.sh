@@ -43,18 +43,19 @@ export JAX_ENABLE_X64="${JAX_ENABLE_X64:-0}"
 export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
 
 # -- XLA flags: step markers at the outer while loop (the decode scan) so
-# profiles bucket per decode chunk; TPU-only flags (latency-hiding
-# scheduling for the offload/collective overlap) only where a TPU chip is
-# attached — CPU/GPU jaxlib aborts on unregistered flags;
-# REPRO_HOST_DEVICES forces a CPU device mesh
+# profiles bucket per decode chunk; REPRO_HOST_DEVICES forces a CPU
+# device mesh.  XLA_FLAGS holds only flags jaxlib registers (it aborts on
+# any other).  TPU compiler flags go to libtpu through LIBTPU_INIT_ARGS,
+# appended to what the machine already sets there, never replacing it.
 xla_flags="--xla_step_marker_location=STEP_MARK_AT_TOP_LEVEL_WHILE_LOOP"
-if compgen -G "/dev/accel*" > /dev/null || [[ -c /dev/vfio/vfio ]]; then
-    xla_flags="$xla_flags --xla_tpu_enable_latency_hiding_scheduler=true"
-fi
 if [[ -n "${REPRO_HOST_DEVICES:-}" ]]; then
     xla_flags="$xla_flags --xla_force_host_platform_device_count=${REPRO_HOST_DEVICES}"
 fi
 export XLA_FLAGS="$xla_flags${XLA_EXTRA_FLAGS:+ $XLA_EXTRA_FLAGS}${XLA_FLAGS:+ $XLA_FLAGS}"
+if compgen -G "/dev/accel*" > /dev/null || [[ -c /dev/vfio/vfio ]]; then
+    # latency-hiding scheduling for the offload/collective overlap
+    export LIBTPU_INIT_ARGS="${LIBTPU_INIT_ARGS:+$LIBTPU_INIT_ARGS }--xla_tpu_enable_latency_hiding_scheduler=true"
+fi
 
 export PYTHONPATH="$repo_root/src${PYTHONPATH:+:$PYTHONPATH}"
 

@@ -50,18 +50,30 @@ admits, retires, and refills requests between chunks:
   ``('model',)`` mesh, decode runs resident-expert partials + psum under
   shard_map, and the offload meter splits into per-shard stores whose
   link bytes reduce into the report.  On CPU this needs
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=N``;
+- ``--full-config --layers N``: the published widths at a depth cut to
+  N layers, so a model whose full depth does not fit one device still
+  serves at full width.  Full-width weights are drawn in bf16 (the
+  reduced CPU config keeps f32) and, with ``--offload``, compressed one
+  layer at a time, so the dense model never exists whole on the device.
+
+``build_engine(args)`` is the one way an engine is built from these
+options; other entry points (``chip_smoke.py``) call it with
+``build_parser().parse_args([...])``.
 """
 import argparse
+import dataclasses
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..config import ControlConfig
+from ..config import ControlConfig, ModelConfig
 from ..registry import get_config
 from ..models import init_params
-from ..models.transformer import compress_moe_params
+from ..models.transformer import init_compressed_params
+from ..paths import enable_compile_cache
 from ..serve import ServeEngine, synthetic_workload
 from .mesh import make_serve_mesh, parse_mesh_spec
 
@@ -93,7 +105,7 @@ def _maybe_autotune(stacks_by_layer):
                   f"bm,bn,bk={tiles}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="serve synthetic traffic through the continuous-"
                     "batching engine (scheduler + fixed-shape scan chunks)")
@@ -102,7 +114,12 @@ def main():
                     help="fixed-batch mode: rows decoded side by side")
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--full-config", action="store_true")
+    ap.add_argument("--full-config", action="store_true",
+                    help="published widths (bf16 weights) instead of the "
+                         "reduced CPU config (f32)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="with --full-config: cut the depth to this many "
+                         "layers at the published widths (0 = all)")
     ap.add_argument("--requests", type=int, default=0,
                     help="schedule N ragged requests through the slot pool "
                          "instead of one fixed batch")
@@ -180,20 +197,15 @@ def main():
     ap.add_argument("--stream-fallback-bits", type=int, default=2,
                     help="bit width of the device-resident fallback copy "
                          "that serves missed experts under 'degrade'")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch, reduced=not args.full_config)
-    if cfg.encoder is not None or cfg.rope_kind == "mrope":
-        print(f"note: {cfg.name} needs frontend inputs; serving the "
-              f"text-only path")
-    # params follow --seed on BOTH paths, so `--offload` (in-memory
-    # compression) and `--offload --artifact` compare bit-identically at
-    # any seed, not just the default 0
-    params = init_params(jax.random.key(args.seed), cfg, jnp.float32)
-    mesh = make_serve_mesh(parse_mesh_spec(args.mesh).get("ep", 1)
-                           if args.mesh else 1)
 
+def validate(ap: argparse.ArgumentParser, args) -> None:
+    """Reject option combinations the engine cannot serve."""
     want_budget = args.bytes_per_token > 0 or args.target_tokens_per_s > 0
+    if args.layers and not args.full_config:
+        ap.error("--layers cuts the depth of the published config; it "
+                 "needs --full-config")
     if want_budget and not args.offload:
         ap.error("--bytes-per-token/--target-tokens-per-s need --offload "
                  "(the controller feeds on the offload byte meters)")
@@ -209,50 +221,113 @@ def main():
     if args.spec_k > 0 and args.requests <= 0:
         ap.error("--spec-k needs --requests (speculative rounds run "
                  "through the continuous-batching scheduler)")
-    if args.offload:
-        if cfg.moe is None:
-            ap.error(f"--offload needs an MoE arch; {cfg.name} has none")
-        if args.artifact:
-            from ..calib import load_compression_artifact
-            from ..models.transformer import apply_compressed_stacks
-            stacks_by_layer, plan, meta = load_compression_artifact(
-                args.artifact, cfg)
-            if meta.get("seed", 0) != args.seed:
-                ap.error(f"artifact was compressed against params seed "
-                         f"{meta.get('seed')}, serving seed {args.seed}")
-            qparams, cfg_q = apply_compressed_stacks(params, cfg,
-                                                     stacks_by_layer)
-            print(f"booted artifact {args.artifact}: "
-                  f"{meta['moe_layers']} MoE layers, "
-                  f"plan={'none (uniform)' if plan is None else plan.scorer},"
-                  f" checksum ok — no startup recompression")
-        else:
-            qparams, cfg_q, stacks_by_layer = compress_moe_params(params,
-                                                                  cfg)
-        _maybe_autotune(stacks_by_layer)
-        eng = ServeEngine(cfg_q, qparams, quantized=True, mesh=mesh)
-        eng.attach_offload(stacks_by_layer, policy="ours",
-                           cache_capacity=args.cache_experts)
-        if want_budget:
-            eng.attach_controller(ControlConfig(
-                enabled=True, bytes_per_token=args.bytes_per_token,
-                tokens_per_s=args.target_tokens_per_s,
-                link_bw=args.link_bw, budget_scope=args.budget_scope))
-        if args.stream:
-            from ..config import StreamConfig
-            eng.attach_streaming(StreamConfig(
-                enabled=True, ring_slots=args.stream_ring,
-                miss_policy=args.stream_miss,
-                fallback_bits=args.stream_fallback_bits))
-    else:
-        eng = ServeEngine(cfg, params, mesh=mesh)
+    if args.offload and model_config(args).moe is None:
+        ap.error(f"--offload needs an MoE arch; {args.arch} has none")
+
+
+def model_config(args) -> ModelConfig:
+    cfg = get_config(args.arch, reduced=not args.full_config)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def serve_mesh(args):
+    return make_serve_mesh(parse_mesh_spec(args.mesh).get("ep", 1)
+                           if args.mesh else 1)
+
+
+def build_model(args, mesh=None
+                ) -> Tuple[ModelConfig, Dict, Optional[List[Dict]]]:
+    """``(cfg, params, stacks_by_layer)`` to serve: dense params (stacks
+    None) or, with ``--offload``, the compressed model and its per-layer
+    stacks.  Weights follow ``--seed`` on every path, so ``--offload``
+    (in-memory compression) and ``--offload --artifact`` compare
+    bit-identically at any seed.  With a ``mesh`` each compressed layer
+    is placed on it as soon as it exists."""
+    cfg = model_config(args)
+    if cfg.encoder is not None or cfg.rope_kind == "mrope":
+        print(f"note: {cfg.name} needs frontend inputs; serving the "
+              f"text-only path")
+    dtype = jnp.bfloat16 if args.full_config else jnp.float32
+    key = jax.random.key(args.seed)
+    if not args.offload:
+        return cfg, init_params(key, cfg, dtype), None
+    if args.artifact:
+        from ..calib import load_compression_artifact
+        from ..models.transformer import apply_compressed_stacks
+        stacks_by_layer, plan, meta = load_compression_artifact(
+            args.artifact, cfg)
+        if meta.get("seed", 0) != args.seed:
+            raise ValueError(f"artifact was compressed against params seed "
+                             f"{meta.get('seed')}, serving seed {args.seed}")
+        qparams, cfg_q = apply_compressed_stacks(
+            init_params(key, cfg, dtype), cfg, stacks_by_layer)
+        print(f"booted artifact {args.artifact}: "
+              f"{meta['moe_layers']} MoE layers, "
+              f"plan={'none (uniform)' if plan is None else plan.scorer},"
+              f" checksum ok — no startup recompression")
+        return cfg_q, qparams, stacks_by_layer
+    place = None
+    if mesh is not None:
+        from ..config import ParallelConfig
+        from ..distributed.sharding import tree_shardings
+
+        def place(tree):
+            return jax.device_put(tree, tree_shardings(
+                mesh, jax.eval_shape(lambda: tree), ParallelConfig()))
+    qparams, cfg_q, stacks_by_layer = init_compressed_params(
+        key, cfg, dtype, place=place)
+    return cfg_q, qparams, stacks_by_layer
+
+
+def build_engine(args, model=None) -> ServeEngine:
+    """The serving engine the options describe.  ``model`` (a
+    ``build_model`` result) reuses weights already built, e.g. to serve
+    the same compressed model resident and streamed."""
+    mesh = serve_mesh(args)
+    cfg, params, stacks_by_layer = model or build_model(args, mesh)
+    if stacks_by_layer is None:
+        return ServeEngine(cfg, params, mesh=mesh)
+    _maybe_autotune(stacks_by_layer)
+    eng = ServeEngine(cfg, params, quantized=True, mesh=mesh)
+    eng.attach_offload(stacks_by_layer, policy="ours",
+                       cache_capacity=args.cache_experts)
+    if args.bytes_per_token > 0 or args.target_tokens_per_s > 0:
+        eng.attach_controller(ControlConfig(
+            enabled=True, bytes_per_token=args.bytes_per_token,
+            tokens_per_s=args.target_tokens_per_s,
+            link_bw=args.link_bw, budget_scope=args.budget_scope))
+    if args.stream:
+        from ..config import StreamConfig
+        eng.attach_streaming(StreamConfig(
+            enabled=True, ring_slots=args.stream_ring,
+            miss_policy=args.stream_miss,
+            fallback_bits=args.stream_fallback_bits))
+    return eng
+
+
+def workload(args, cfg: ModelConfig):
+    """The ``--requests`` workload: ragged prompts from ``--seed``."""
+    return synthetic_workload(
+        args.requests, cfg.vocab_size, rate=args.rate,
+        max_new=args.max_new, min_len=max(args.prompt_len // 2, 1),
+        max_len=args.prompt_len, seed=args.seed)
+
+
+def main():
+    enable_compile_cache()
+    ap = build_parser()
+    args = ap.parse_args()
+    validate(ap, args)
+    try:
+        eng = build_engine(args)
+    except ValueError as exc:
+        ap.error(str(exc))
+    cfg = eng.cfg
 
     if args.requests > 0:
-        reqs = synthetic_workload(
-            args.requests, cfg.vocab_size, rate=args.rate,
-            max_new=args.max_new, min_len=max(args.prompt_len // 2, 1),
-            max_len=args.prompt_len, seed=args.seed)
-        stats = eng.serve(reqs, num_slots=args.slots, chunk=args.chunk,
+        stats = eng.serve(workload(args, cfg), num_slots=args.slots, chunk=args.chunk,
                           seed=args.seed, page_size=args.page_size,
                           prefix_cache=args.prefix_cache,
                           spec_k=args.spec_k,

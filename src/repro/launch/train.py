@@ -9,11 +9,13 @@ import argparse
 import jax
 
 from ..config import TrainConfig
+from ..paths import enable_compile_cache
 from ..registry import get_config
 from ..train import train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -27,8 +27,8 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 
-from ..compat import axis_size
 from ..config import MoEConfig
 from .expert_backend import (ExpertBackend, expert_ffn_dense,
                              select_backend)

@@ -258,9 +258,8 @@ def init_layer(key, spec: LayerSpec, cfg: ModelConfig, dtype):
     return p
 
 
-def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict:
-    plan = derive_plan(cfg)
-    keys = jax.random.split(key, len(plan) + 4)
+def _init_outer(keys, cfg: ModelConfig, dtype) -> Dict:
+    """Embedding, final norm and head: the params outside the stack."""
     params: Dict[str, Any] = {
         "embed": {"tok": embed_init(keys[0], (cfg.vocab_size, cfg.d_model),
                                     dtype)},
@@ -270,21 +269,53 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict:
         params["head"] = {"w": dense_init(keys[1], (cfg.d_model,
                                                     cfg.vocab_size),
                                           cfg.d_model, dtype)}
-    segs = []
+    return params
+
+
+# one layer's draw, compiled whole so that no float32 temporary of the
+# random draw outlives the layer's own arrays (a full-width layer's
+# dense experts are GBs)
+_draw_layer = jax.jit(init_layer, static_argnums=(1, 2, 3))
+
+
+def _draw_params(key, cfg: ModelConfig, dtype, finish: Callable,
+                 place: Callable = lambda tree: tree):
+    """The one key schedule of every param init.
+
+    Draws the params outside the stack, then every layer of the plan
+    unrolled in order, each passed through ``finish(params, spec)`` as
+    soon as it is drawn, then the encoder (None without one); the outer
+    params and the encoder go through ``place`` as soon as they are
+    drawn.  Returns ``(outer, layers, encoder)``; ``layers[si][r][pi]``
+    is what ``finish`` made of segment ``si``'s repeat ``r``, position
+    ``pi``.
+    """
+    plan = derive_plan(cfg)
+    keys = jax.random.split(key, len(plan) + 4)
+    outer = place(_init_outer(keys, cfg, dtype))
+    layers = []
     for si, seg in enumerate(plan):
         skeys = jax.random.split(keys[2 + si], seg.repeat)
-        pos_params = []
-        for pi, spec in enumerate(seg.layers):
-            def one(k, spec=spec):
-                return init_layer(jax.random.fold_in(k, pi), spec, cfg, dtype)
-            if seg.repeat == 1:
-                pos_params.append(one(skeys[0]))
-            else:
-                pos_params.append(jax.vmap(one)(skeys))
-        segs.append(tuple(pos_params))
-    params["segments"] = tuple(segs)
-    if cfg.encoder is not None:
-        params["encoder"] = init_encoder_params(keys[-1], cfg, dtype)
+        layers.append([
+            tuple(finish(_draw_layer(jax.random.fold_in(skeys[r], pi), spec,
+                                     cfg, dtype), spec)
+                  for pi, spec in enumerate(seg.layers))
+            for r in range(seg.repeat)])
+    encoder = (place(init_encoder_params(keys[-1], cfg, dtype))
+               if cfg.encoder is not None else None)
+    return outer, layers, encoder
+
+
+def init_params(key, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict:
+    outer, layers, encoder = _draw_params(key, cfg, dtype,
+                                          lambda lp, spec: lp)
+    params = dict(outer)
+    params["segments"] = tuple(
+        reps[0] if len(reps) == 1
+        else jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+        for reps in layers)
+    if encoder is not None:
+        params["encoder"] = encoder
     return params
 
 
@@ -356,30 +387,67 @@ def compress_moe_params(params, cfg: ModelConfig, qcfg=None, plan=None,
     makes the compensator SVDs activation-weighted.  Both None keeps the
     paper's kurtosis-guided uniform-bit path bit-identically.
     """
-    from ..core.pipeline import compress_ffn_weights
     qcfg = qcfg or cfg.moe.quant
     up = unstack_params(params, cfg)
-    specs = layer_specs(cfg)
     segs, stacks_by_layer = [], []
-    li = 0
-    for (lp,), spec in zip(up["segments"], specs):
-        lp = dict(lp)
-        if spec.ffn == "moe":
-            alloc = plan.layers[li] if plan is not None else None
-            lstats = stats[li] if stats is not None else None
-            mp = dict(lp["moe"])
-            stacks, _ = compress_ffn_weights(mp["w1"], mp["w2"], mp["w3"],
-                                             qcfg, allocation=alloc,
-                                             stats=lstats)
-            stacks_by_layer.append(stacks)
-            mp["stacks"] = stacks
-            for k in ("w1", "w2", "w3"):
-                mp.pop(k)
-            lp["moe"] = mp
-            li += 1
+    for (lp,), spec in zip(up["segments"], layer_specs(cfg)):
+        lp = _compress_layer(lp, spec, qcfg, plan, stats, stacks_by_layer)
         segs.append((lp,))
     qparams = dict(up)
     qparams["segments"] = tuple(segs)
+    return (qparams, dataclasses.replace(cfg, force_unroll_plan=True),
+            stacks_by_layer)
+
+
+def _compress_layer(lp, spec: LayerSpec, qcfg, plan, stats,
+                    stacks_by_layer: List[Dict]) -> Dict:
+    """One unrolled layer's params with its MoE experts compressed (the
+    stacks are also appended to ``stacks_by_layer``)."""
+    from ..core.pipeline import compress_ffn_weights
+    lp = dict(lp)
+    if spec.ffn != "moe":
+        return lp
+    li = len(stacks_by_layer)
+    mp = dict(lp["moe"])
+    stacks, _ = compress_ffn_weights(
+        mp.pop("w1"), mp.pop("w2"), mp.pop("w3"), qcfg,
+        allocation=plan.layers[li] if plan is not None else None,
+        stats=stats[li] if stats is not None else None)
+    stacks_by_layer.append(stacks)
+    mp["stacks"] = stacks
+    lp["moe"] = mp
+    return lp
+
+
+def init_compressed_params(key, cfg: ModelConfig, dtype=jnp.bfloat16,
+                           place: Optional[Callable] = None):
+    """``compress_moe_params(init_params(key, cfg, dtype), cfg)``, built
+    one layer at a time.
+
+    The same keys draw the same weights, but only one layer's dense
+    experts exist at any moment, so a model whose dense form does not
+    fit the device still boots compressed.  ``place`` (optional) maps
+    each finished layer's params, e.g. onto a serving mesh, before the
+    next layer is drawn, so the model is never whole on one device.
+    Returns ``(qparams, cfg_q, stacks_by_layer)`` like
+    ``compress_moe_params``.
+    """
+    place = place or (lambda tree: tree)
+    stacks_by_layer: List[Dict] = []
+
+    def finish(lp, spec):
+        lp = place(_compress_layer(lp, spec, cfg.moe.quant, None, None,
+                                   stacks_by_layer))
+        if spec.ffn == "moe":
+            stacks_by_layer[-1] = lp["moe"]["stacks"]
+        return lp
+
+    outer, layers, encoder = _draw_params(key, cfg, dtype, finish, place)
+    qparams = dict(outer)
+    qparams["segments"] = tuple((lp,) for reps in layers for rep in reps
+                                for lp in rep)
+    if encoder is not None:
+        qparams["encoder"] = encoder
     return (qparams, dataclasses.replace(cfg, force_unroll_plan=True),
             stacks_by_layer)
 
@@ -727,6 +795,16 @@ def _rope(q, k, cfg: ModelConfig, kind: str, positions, mrope_pos):
     return apply_rope(q, positions, theta), apply_rope(k, positions, theta)
 
 
+def _heads_out(out, wo):
+    """The out-projection, summed over heads in float32 and rounded once:
+    a serve mesh shards the heads, and a shard that rounded its partial
+    sum to bf16 before the all-reduce would change the tokens with the
+    shard count."""
+    y = jnp.einsum("bshk,hkd->bsd", out, wo,
+                   preferred_element_type=jnp.float32)
+    return y.astype(jnp.result_type(out, wo))
+
+
 def _attn_layer(x, ap, cfg: ModelConfig, ctx: ExecContext, spec: LayerSpec,
                 positions, cache, mrope_pos, enc_out):
     window = cfg.window_size if spec.mixer == "local" else None
@@ -775,7 +853,7 @@ def _attn_layer(x, ap, cfg: ModelConfig, ctx: ExecContext, spec: LayerSpec,
             upd = prefill_attn_cache({kk: cache[kk] for kk in kv_keys},
                                      k, v, positions)
             new_cache.update(upd)
-    y = jnp.einsum("bshk,hkd->bsd", out, ap["wo"])
+    y = _heads_out(out, ap["wo"])
     # cross-attention (enc-dec decoder)
     if spec.cross:
         xc = rms_norm(x + y, ap["cross_norm"], cfg.norm_eps)
@@ -794,7 +872,7 @@ def _attn_layer(x, ap, cfg: ModelConfig, ctx: ExecContext, spec: LayerSpec,
                        jnp.zeros_like(positions) + src,  # no causal masking
                        src_pos, causal=False, q_block=ctx.q_block,
                        unroll=ctx.scan_unroll)
-        y = y + jnp.einsum("bshk,hkd->bsd", co, ap["cross_wo"])
+        y = y + _heads_out(co, ap["cross_wo"])
     return y, new_cache
 
 

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.pipeline import CompressedExpertStack
-from ..core.quantize import PLANES, _group_minmax, pack_bits
+from ..core.quantize import PLANES, _group_minmax, dequantize, pack_bits
 
 # leaves that move with a weight fetch vs a factor fetch
 WEIGHT_LEAVES = ("planes", "scale", "zero")
@@ -133,11 +133,12 @@ def build_fallback_stack(stack: CompressedExpertStack,
     the jitted decode loop's signature.
     """
     fb = _clamp_fallback_bits(fallback_bits, stack.bits)
-    w = stack.dequantize_all()                    # (E, K, N) f32
     G = stack.group_size
     qmax = (1 << fb) - 1
 
-    def _rtn_one(we):
+    def _rtn_one(e):
+        # one expert at a time: no (E, K, N) float32 temporary
+        we = dequantize(stack.expert_qt(e))
         g, lo, hi = _group_minmax(we, G)
         scale = jnp.maximum((hi - lo) / qmax, 1e-8)
         zero = -lo / scale
@@ -147,12 +148,16 @@ def build_fallback_stack(stack: CompressedExpertStack,
         return (pack_bits(q, stack.bits), scale.reshape(-1, n),
                 zero.reshape(-1, n))
 
-    planes, scale, zero = jax.vmap(_rtn_one)(w)
+    per_expert = [_rtn_one(e) for e in range(stack.scale.shape[0])]
+    planes = tuple(jnp.stack([pe[0][i] for pe in per_expert])
+                   for i in range(len(per_expert[0][0])))
     return dataclasses.replace(
         stack,
-        planes=tuple(jnp.asarray(p) for p in planes),
-        scale=scale.astype(stack.scale.dtype),
-        zero=zero.astype(stack.zero.dtype),
+        planes=planes,
+        scale=jnp.stack([pe[1] for pe in per_expert]).astype(
+            stack.scale.dtype),
+        zero=jnp.stack([pe[2] for pe in per_expert]).astype(
+            stack.zero.dtype),
         u=jnp.zeros_like(stack.u), v=jnp.zeros_like(stack.v),
         u_scale=jnp.zeros_like(stack.u_scale),
         v_scale=jnp.zeros_like(stack.v_scale))

@@ -53,6 +53,7 @@ def test_roofline_candidates_fit_vmem_and_problem():
 def test_record_and_disk_roundtrip(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
                        str(tmp_path / "autotune.json"))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")   # tuning runs read the table
     t = Autotuner()
     t.record("fused", (8, 128, 256), 42.0, bits=2, group_size=64,
              rank=16, m=8, k=512, n=512)
@@ -107,6 +108,7 @@ def test_store_disk_is_atomic(tmp_path, monkeypatch):
 def test_corrupt_disk_cache_falls_back_to_defaults(tmp_path, monkeypatch):
     cache = tmp_path / "autotune.json"
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
     expected = Autotuner().choose("fused", bits=2, group_size=64, rank=16,
                                   m=8, k=1024, n=1024)
     for payload in ('{"truncated', '[1, 2, 3]', '"just a string"', ""):
@@ -124,6 +126,7 @@ def test_structurally_corrupt_entry_is_ignored(tmp_path, monkeypatch):
     """Valid JSON whose entries have the wrong shape must not raise."""
     cache = tmp_path / "autotune.json"
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
     expected = Autotuner().choose("fused", bits=2, group_size=64, rank=16,
                                   m=8, k=1024, n=1024)
     key = "fused/b2/g64/r16/m8/k1024/n1024"
@@ -133,3 +136,64 @@ def test_structurally_corrupt_entry_is_ignored(tmp_path, monkeypatch):
         cache.write_text(json.dumps({device_kind(): {key: bad}}))
         assert Autotuner().choose("fused", bits=2, group_size=64, rank=16,
                                   m=8, k=1024, n=1024) == expected
+
+
+def test_persisted_table_not_read_unless_tuning(tmp_path, monkeypatch):
+    """A default run depends on tracked files alone: a persisted winner
+    is looked up only when REPRO_AUTOTUNE asks for tuning."""
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
+                       str(tmp_path / "autotune.json"))
+    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
+    Autotuner().record("fused", (8, 128, 256), 42.0, bits=2, group_size=64,
+                       rank=16, m=8, k=512, n=512)
+    assert Autotuner().choose("fused", bits=2, group_size=64, rank=16,
+                              m=8, k=512, n=512) == (8, 256, 512)
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    assert Autotuner().choose("fused", bits=2, group_size=64, rank=16,
+                              m=8, k=512, n=512) == (8, 128, 256)
+
+
+def test_default_cache_path_is_inside_the_checkout(monkeypatch):
+    from repro.paths import CHECKOUT
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    assert autotune.cache_path().parent == CHECKOUT
+
+
+def test_device_kind_raises_without_a_device(monkeypatch):
+    import jax
+    import pytest
+
+    def no_devices():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(jax, "devices", no_devices)
+    with pytest.raises(RuntimeError, match="no backend"):
+        autotune.device_kind()
+
+
+def test_tune_fused_reports_failures_and_raises_when_all_fail(
+        tmp_path, monkeypatch):
+    import pytest
+    from repro.config import QuantConfig
+    from repro.core.pipeline import compress_expert_stack
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
+                       str(tmp_path / "autotune.json"))
+    rng = np.random.default_rng(0)
+    qcfg = QuantConfig(enabled=True, bits=2, group_size=64, rank_budget=8,
+                       top_n_restore=1, hqq_iters=1)
+    w = jnp.asarray(rng.standard_normal((2, 128, 128)), jnp.float32) * 0.05
+    stack, _ = compress_expert_stack(w, qcfg)
+    xe = jnp.asarray(rng.standard_normal((2, 8, 128)), jnp.float32)
+    me = jnp.ones((2, 8), jnp.float32)
+
+    def refused(*a, **k):
+        raise ValueError("block shape refused")
+
+    monkeypatch.setattr(ops, "fused_expert_matmul", refused)
+    with pytest.warns(UserWarning, match="block shape refused"):
+        with pytest.raises(RuntimeError, match="every fused tile"):
+            autotune.tune_fused(xe, stack, me, None, None,
+                                out_dtype=jnp.float32, interpret=True,
+                                repeats=1)

@@ -4,7 +4,8 @@ One multi-device script (via the shared ``dist_run`` fixture) serves the
 same request set through ``ServeEngine`` across the full parity matrix
 
     ``REPRO_KERNEL_IMPL`` in {ref, pallas_interpret}
-  x arch in {MoE (E=8, k=2), dense-degenerate (E=1, k=1)}
+  x arch in {MoE (E=8, k=2), dense-degenerate (E=1, k=1), MoE with bf16
+             weights and 8 query heads, which the mesh shards}
   x shard counts {1, 2, 8}
 
 and the tests pin:
@@ -33,7 +34,7 @@ import pytest
 pytestmark = pytest.mark.dist
 
 IMPLS = ("ref", "pallas_interpret")
-ARCHS = ("moe", "dense_e1")
+ARCHS = ("moe", "dense_e1", "moe_bf16")
 EPS = (1, 2, 8)
 
 SCRIPT = textwrap.dedent("""
@@ -49,10 +50,11 @@ SCRIPT = textwrap.dedent("""
     from repro.models.transformer import compress_moe_params
     from repro.serve import ServeEngine, synthetic_workload
 
-    def make_cfg(e, k):
+    def make_cfg(e, k, heads=(2, 1)):
         return ModelConfig(
             name=f"ep-serve-{e}", family="moe", num_layers=2, d_model=64,
-            num_heads=2, num_kv_heads=1, head_dim=32, d_ff=0, vocab_size=64,
+            num_heads=heads[0], num_kv_heads=heads[1], head_dim=32, d_ff=0,
+            vocab_size=64,
             block_pattern=("global",), max_position=512,
             moe=MoEConfig(num_experts=e, top_k=k, d_expert=64,
                           quant=QuantConfig(enabled=True, bits=2,
@@ -63,9 +65,12 @@ SCRIPT = textwrap.dedent("""
                for i in range(3)]
     results = {}
 
-    for arch, (e, k) in (("moe", (8, 2)), ("dense_e1", (1, 1))):
-        cfg = make_cfg(e, k)
-        params = init_params(jax.random.key(0), cfg, jnp.float32)
+    for arch, (e, k, heads, dtype) in (
+            ("moe", (8, 2, (2, 1), jnp.float32)),
+            ("dense_e1", (1, 1, (2, 1), jnp.float32)),
+            ("moe_bf16", (8, 2, (8, 2), jnp.bfloat16))):
+        cfg = make_cfg(e, k, heads)
+        params = init_params(jax.random.key(0), cfg, dtype)
         qparams, cfg_q, stacks = compress_moe_params(params, cfg)
         for impl in ("ref", "pallas_interpret"):
             for ep in (1, 2, 8):

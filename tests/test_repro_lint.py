@@ -93,6 +93,12 @@ FIXTURES = {
         def launch(planes, x, bk=128):
             return pl.pallas_call(kern)(planes[0])
         """},
+    "RL304": {"kernels/k.py": """
+        from jax.experimental import pallas as pl
+
+        def launch(me, bm):
+            return pl.BlockSpec((1, bm), lambda e, i: (e, i))
+        """},
     "RL401": {"m.py": """
         import jax
         from repro.distributed.sharding import tree_constraint
@@ -153,6 +159,21 @@ def test_pack_guard_silences_rl303(tmp_path):
     assert result.findings == []
 
 
+def test_rl304_checks_local_block_helpers_and_spares_legal_blocks(tmp_path):
+    result = run_fixture(tmp_path, {"kernels/k.py": """
+        from jax.experimental import pallas as pl
+
+        def launch(bm, bk, r):
+            def spec(shape, index):
+                return pl.BlockSpec(shape, index)
+            legal = [spec((1, bm, 1), None), spec((1, 1, r), None),
+                     pl.BlockSpec((bm, bk), None)]
+            return legal + [spec((1, 1), None)]
+        """}, select={"RL304"})
+    assert [f.content for f in result.findings] == [
+        "return legal + [spec((1, 1), None)]"]
+
+
 # ---------------------------------------------------------------------------
 # the real tree is clean
 # ---------------------------------------------------------------------------
@@ -169,7 +190,7 @@ def test_real_tree_is_clean():
 def test_all_rules_registered():
     ids = set(all_rules())
     assert {"RL101", "RL102", "RL103", "RL104", "RL201", "RL202",
-            "RL301", "RL302", "RL303", "RL401"} <= ids
+            "RL301", "RL302", "RL303", "RL304", "RL401"} <= ids
 
 
 # ---------------------------------------------------------------------------
