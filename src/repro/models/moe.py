@@ -41,6 +41,7 @@ class RoutingInfo(NamedTuple):
     logits: jax.Array       # (T, E)
 
 
+@jax.named_scope("router")
 def route(x2: jax.Array, w_router: jax.Array, mcfg: MoEConfig) -> RoutingInfo:
     """x2: (T, d) -> routing for top-k experts (softmax-then-topk)."""
     logits = jnp.einsum("td,de->te", x2.astype(jnp.float32),
@@ -77,6 +78,7 @@ class Dispatch(NamedTuple):
     capacity: int
 
 
+@jax.named_scope("router")
 def make_dispatch(info: RoutingInfo, num_experts: int, capacity: int,
                   top_n) -> Dispatch:
     """``top_n`` may be a static int or a traced scalar (the bandwidth
@@ -94,6 +96,7 @@ def make_dispatch(info: RoutingInfo, num_experts: int, capacity: int,
                     capacity)
 
 
+@jax.named_scope("router")
 def dispatch_tokens(x2: jax.Array, d: Dispatch, num_experts: int
                     ) -> Tuple[jax.Array, jax.Array]:
     """Scatter (T, dm) tokens into (E, C, dm) expert buffers + comp mask."""
@@ -105,6 +108,7 @@ def dispatch_tokens(x2: jax.Array, d: Dispatch, num_experts: int
     return xe, me
 
 
+@jax.named_scope("router")
 def dispatch_gates(d: Dispatch, num_experts: int) -> jax.Array:
     """Scatter router gates into the (E, C) slot layout.
 
@@ -117,6 +121,7 @@ def dispatch_gates(d: Dispatch, num_experts: int) -> jax.Array:
     return ge.at[d.e_idx, d.slot].set(d.gates, mode="drop")
 
 
+@jax.named_scope("combine")
 def combine_tokens(ye: jax.Array, d: Dispatch, num_tokens: int, *,
                    pre_weighted: bool = False) -> jax.Array:
     """Gather (E, C, dm) expert outputs back to (T, dm), gate-weighted.

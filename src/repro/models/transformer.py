@@ -832,20 +832,24 @@ def _attn_layer(x, ap, cfg: ModelConfig, ctx: ExecContext, spec: LayerSpec,
                 k, v, positions)
             new_cache.update(upd)
             kf, vf, posf, ksf, vsf = paged_gather(upd)
-            out = decode_attention(q, kf, vf, posf, positions,
-                                   window=window, k_scale=ksf, v_scale=vsf)
+            with jax.named_scope("attention"):
+                out = decode_attention(q, kf, vf, posf, positions,
+                                       window=window, k_scale=ksf,
+                                       v_scale=vsf)
         else:
             upd = update_attn_cache({kk: cache[kk] for kk in kv_keys},
                                     k, v, positions)
             new_cache.update(upd)
-            out = decode_attention(q, upd["k"], upd["v"], upd["pos"],
-                                   positions, window=window,
-                                   k_scale=upd.get("k_scale"),
-                                   v_scale=upd.get("v_scale"))
+            with jax.named_scope("attention"):
+                out = decode_attention(q, upd["k"], upd["v"], upd["pos"],
+                                       positions, window=window,
+                                       k_scale=upd.get("k_scale"),
+                                       v_scale=upd.get("v_scale"))
     else:
-        out = attention(q, k, v, positions, positions, causal=True,
-                        window=window, q_block=ctx.q_block,
-                        unroll=ctx.scan_unroll)
+        with jax.named_scope("attention"):
+            out = attention(q, k, v, positions, positions, causal=True,
+                            window=window, q_block=ctx.q_block,
+                            unroll=ctx.scan_unroll)
         if ctx.mode == "prefill" and cache is not None:
             new_cache = dict(cache)
             kv_keys = ("k", "v", "pos") + (("k_scale", "v_scale")
@@ -868,10 +872,11 @@ def _attn_layer(x, ap, cfg: ModelConfig, ctx: ExecContext, spec: LayerSpec,
                 new_cache["cross_v"] = cv.astype(new_cache["cross_v"].dtype)
         src = ck.shape[1]
         src_pos = jnp.broadcast_to(jnp.arange(src), (ck.shape[0], src))
-        co = attention(qc, ck, cv,
-                       jnp.zeros_like(positions) + src,  # no causal masking
-                       src_pos, causal=False, q_block=ctx.q_block,
-                       unroll=ctx.scan_unroll)
+        with jax.named_scope("attention"):
+            co = attention(qc, ck, cv,
+                           jnp.zeros_like(positions) + src,  # no causal mask
+                           src_pos, causal=False, q_block=ctx.q_block,
+                           unroll=ctx.scan_unroll)
         y = y + _heads_out(co, ap["cross_wo"])
     return y, new_cache
 

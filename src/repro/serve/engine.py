@@ -60,6 +60,7 @@ from .controller import BandwidthController, ControllerPlan
 from .paging import PagePool, prefix_page_hashes
 from .scheduler import Request, RequestResult, Scheduler
 from .speculative import accept_drafts, make_drafter, mask_banned
+from .timeline import Timeline
 
 PROMPT_BUCKET_MIN = 16     # smallest padded-prompt length
 CACHE_BUCKET_MIN = 32      # smallest bucketed cache length
@@ -111,7 +112,12 @@ class ServeStats:
     num_slots: int
     chunk: int
     total_s: float
+    # host wall time inside the timeline's ``prefill`` + ``claim`` spans:
+    # mostly the asynchronous dispatch, not the device's prefill work
     prefill_s: float
+    # host wall time inside ``decode.dispatch`` + ``decode.sync``: the
+    # sync also waits out the device time of every prefill and claim
+    # queued ahead of the chunk
     decode_s: float
     chunks: int
     generated_tokens: int              # accepted tokens across requests
@@ -140,6 +146,8 @@ class ServeStats:
     # speculative decoding (serve(spec_k=)): draft acceptance rate,
     # lookahead prefetch accuracy, draft overhead bytes (None = spec off)
     spec_report: Optional[Dict] = None
+    # the serve loop's host spans and counters (serve/timeline.py)
+    timeline: Optional[Timeline] = None
 
     def __post_init__(self):
         # zero-token requests carry first_token_s = NaN (an explicit
@@ -164,21 +172,24 @@ class ServeStats:
 
     @property
     def busy_s(self) -> float:
-        """Engine busy time: prefill + decode compute, excluding the idle
-        gaps where the scheduler sat waiting on request arrivals."""
+        """Host wall time inside the prefill, claim and decode spans
+        (``prefill_s + decode_s``), which leaves out the arrival waits.
+        Not device busy time: prefill device work lands in ``decode_s``,
+        and the host's own work between chunks is in neither."""
         return self.prefill_s + self.decode_s
 
     @property
     def goodput_tokens_per_s(self) -> float:
-        """Accepted tokens per *busy* second.  Under open-loop (rated)
-        traffic the wall-clock ``tokens_per_s`` folds arrival idle time
-        into the denominator and collapses as the offered rate drops;
-        goodput is the engine-capacity view that stays comparable across
-        offered loads."""
+        """Accepted tokens per ``busy_s`` second (host wall time, see
+        there).  Under open-loop (rated) traffic the wall-clock
+        ``tokens_per_s`` folds arrival idle time into the denominator
+        and collapses as the offered rate drops; this ratio leaves the
+        waits out, so it compares better across offered loads."""
         return (self.generated_tokens / self.busy_s) if self.busy_s else 0.0
 
     @property
     def busy_frac(self) -> float:
+        """``busy_s`` over ``total_s``: both host wall time."""
         return self.busy_s / self.total_s if self.total_s else 0.0
 
     def latency_percentiles(self, qs: Sequence[float] = (50.0, 95.0)
@@ -292,12 +303,16 @@ class ServeEngine:
 
             def body(carry, _):
                 logits, caches, key = carry
-                key, k2 = jax.random.split(key)
-                nxt = sample(logits, k2, temperature)
+                with jax.named_scope("sampling"):
+                    key, k2 = jax.random.split(key)
+                    nxt = sample(logits, k2, temperature)
                 out = lm.decode_step(params, nxt[:, None], caches, cfg,
                                      self._step_ctx, plan=plan)
-                lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-                lp_tok = jnp.take_along_axis(lp, nxt[:, None], axis=-1)[:, 0]
+                with jax.named_scope("sampling"):
+                    lp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                            axis=-1)
+                    lp_tok = jnp.take_along_axis(lp, nxt[:, None],
+                                                 axis=-1)[:, 0]
                 ys = (nxt, lp_tok)
                 if self.collect_router_trace:
                     ys = ys + (out.trace,)        # (moe_layers, B, k)
@@ -986,7 +1001,9 @@ class ServeEngine:
         between chunks the scheduler retires finished requests (EOS /
         max-token) and refills their slots from the arrival queue.
         Requests with future ``arrival_s`` wait in the queue (offered-load
-        benchmarking); latencies are wall-clock from arrival.
+        benchmarking); latencies are wall-clock from arrival.  Each step
+        of the loop runs under a host span of ``ServeStats.timeline``
+        (``serve/timeline.py``), on the profiler's clock too.
 
         ``page_size`` (default ``scfg.page_size``; 0 = off) switches the
         cache's global-attention layers to block-table paging: capacity
@@ -1058,7 +1075,8 @@ class ServeEngine:
         order = [r.uid for r in reqs]       # results in submission order
         reqs = sorted(reqs, key=lambda r: r.arrival_s)
         if not reqs:
-            return ServeStats([], num_slots, chunk, 0.0, 0.0, 0.0, 0, 0)
+            return ServeStats([], num_slots, chunk, 0.0, 0.0, 0.0, 0, 0,
+                              timeline=Timeline())
 
         def padded_plen(r: Request) -> int:
             return (bucket_len(r.prompt_len, PROMPT_BUCKET_MIN)
@@ -1115,144 +1133,168 @@ class ServeEngine:
                 if self._stores else None)
         traces: List[np.ndarray] = []
         plans: List[np.ndarray] = []
-        prefill_s = decode_s = 0.0
         chunks = generated = metered_tokens = prefill_tok = 0
         t0 = time.perf_counter()
+        tl = Timeline(t0)
         while sched.has_work():
-            now = time.perf_counter() - t0
-            admits = sched.admit(now)
+            with tl.span("admit"):
+                now = time.perf_counter() - t0
+                admits = sched.admit(now)
             if not admits and sched.num_active == 0:
                 # idle: nothing resident, next request hasn't arrived yet
                 # — sleep the exact gap once (the old 0.25 s cap spun the
                 # loop awake repeatedly under sparse offered load)
-                gap = max(sched.next_arrival() - now, 0.0)
-                time.sleep(gap + 1e-4)
+                with tl.span("arrival_wait"):
+                    gap = max(sched.next_arrival() - now, 0.0)
+                    time.sleep(gap + 1e-4)
                 continue
             for slot, req in admits:
-                tp = time.perf_counter()
-                if paged:
-                    lg, rc, claim_args = self._admit_paged(
-                        req, pool, caches, slot, slot_pages,
-                        max_blocks=max_blocks, page_size=ps,
-                        ring_len=ring_len, use_prefix=use_prefix)
-                    prefill_tok += claim_args.pop("prefill_tokens")
-                else:
-                    lg, rc = self._prefill_request(req, cache_len)
-                    claim_args = None
-                    prefill_tok += padded_plen(req)
-                if logits is None:
-                    logits = jnp.zeros((num_slots,) + lg.shape[1:], lg.dtype)
-                    if self.mesh is not None:
-                        logits = jax.device_put(
-                            logits, self._logits_sharding(logits.shape))
-                if paged:
-                    caches, logits = self._claim_paged(
-                        caches, rc, logits, lg, jnp.int32(slot),
-                        claim_args["pages"], claim_args["write_mask"])
-                else:
-                    caches, logits = self._claim(caches, rc, logits, lg,
-                                                 jnp.int32(slot))
+                tl.count("admissions")
+                with tl.span("prefill", uid=req.uid,
+                             prompt_len=req.prompt_len,
+                             bucket=padded_plen(req)):
+                    if paged:
+                        lg, rc, claim_args = self._admit_paged(
+                            req, pool, caches, slot, slot_pages,
+                            max_blocks=max_blocks, page_size=ps,
+                            ring_len=ring_len, use_prefix=use_prefix)
+                        prefill_tok += claim_args.pop("prefill_tokens")
+                    else:
+                        lg, rc = self._prefill_request(req, cache_len)
+                        claim_args = None
+                        prefill_tok += padded_plen(req)
+                with tl.span("claim", uid=req.uid, slot=slot):
+                    if logits is None:
+                        logits = jnp.zeros((num_slots,) + lg.shape[1:],
+                                           lg.dtype)
+                        if self.mesh is not None:
+                            logits = jax.device_put(
+                                logits, self._logits_sharding(logits.shape))
+                    if paged:
+                        caches, logits = self._claim_paged(
+                            caches, rc, logits, lg, jnp.int32(slot),
+                            claim_args["pages"], claim_args["write_mask"])
+                    else:
+                        caches, logits = self._claim(caches, rc, logits, lg,
+                                                     jnp.int32(slot))
                 if spec_on:
-                    # sample the new tenant's first token from its claim
-                    # logits now (the non-speculative loop does this as
-                    # its first scan step), so the drafter can condition
-                    # its first proposals on it
-                    adm_key, k1 = jax.random.split(adm_key)
-                    t1_new = int(np.asarray(
-                        sample(lg, k1, self.scfg.temperature))[0])
-                    next_t1[slot] = t1_new
-                    # rebind the slot's draft history to the new tenant;
-                    # no residual carries across requests
-                    drafter.reset_slot(slot, np.asarray(req.tokens))
-                    drafter.observe(slot, np.asarray([t1_new]))
-                prefill_s += time.perf_counter() - tp
+                    with tl.span("draft", uid=req.uid):
+                        # sample the new tenant's first token from its
+                        # claim logits now (the non-speculative loop does
+                        # this as its first scan step), so the drafter can
+                        # condition its first proposals on it
+                        adm_key, k1 = jax.random.split(adm_key)
+                        t1_new = int(np.asarray(
+                            sample(lg, k1, self.scfg.temperature))[0])
+                        next_t1[slot] = t1_new
+                        # rebind the slot's draft history to the new
+                        # tenant; no residual carries across requests
+                        drafter.reset_slot(slot, np.asarray(req.tokens))
+                        drafter.observe(slot, np.asarray([t1_new]))
 
-            plan = self._current_plan()
+            with tl.span("plan"):
+                plan = self._current_plan()
+                plan_dev = self._plan_device(plan)
+                if plan is not None:
+                    plans.append(plan.as_array())
+            # the chunk's decode start: per-step stamps interpolate from
+            # here (record_chunk's t_start)
             td = time.perf_counter()
             if spec_on:
-                draft_np = drafter.propose_all(num_slots, spec_k)
-                draft_dev = jnp.asarray(draft_np, jnp.int32)
-                t1_dev = jnp.asarray(next_t1)
-                if self._stream is not None:
+                with tl.span("draft"):
+                    draft_dev = jnp.asarray(
+                        drafter.propose_all(num_slots, spec_k), jnp.int32)
+                    t1_dev = jnp.asarray(next_t1)
+            with tl.span("decode.dispatch"):
+                if spec_on and self._stream is not None:
                     (logits, caches, key, ys), _deg = self._run_spec_round(
                         caches, logits, key, plan, t1_dev, draft_dev,
                         sched.active_mask())
-                else:
+                elif spec_on:
                     logits, caches, key, ys = self._spec_round(
-                        self.params, caches, logits, key,
-                        self._plan_device(plan), t1_dev, draft_dev,
+                        self.params, caches, logits, key, plan_dev, t1_dev,
+                        draft_dev, self.scfg.temperature)
+                elif self._stream is not None:
+                    (logits, caches, key, ys), _deg = self._run_chunk(
+                        caches, logits, key, plan, chunk,
+                        sched.active_mask())
+                else:
+                    logits, caches, key, ys = self._decode_loop(
+                        self.params, caches, logits, key, plan_dev, chunk,
                         self.scfg.temperature)
-            elif self._stream is not None:
-                (logits, caches, key, ys), _deg = self._run_chunk(
-                    caches, logits, key, plan, chunk, sched.active_mask())
-            else:
-                logits, caches, key, ys = self._decode_loop(
-                    self.params, caches, logits, key,
-                    self._plan_device(plan), chunk, self.scfg.temperature)
-            logits.block_until_ready()
-            decode_s += time.perf_counter() - td
-            chunks += 1
-            if plan is not None:
-                plans.append(plan.as_array())
+            with tl.span("decode.sync"):
+                logits.block_until_ready()
 
-            if spec_on:
-                # round outputs are already slot-major (S, k+1); acc_len
-                # crosses to the host HERE, once per round, as one (S,)
-                # array — never a per-token sync inside the jitted round
-                toks = np.asarray(ys[0])
-                lps = np.asarray(ys[1])
+            with tl.span("pull"):
+                if spec_on:
+                    # round outputs are already slot-major (S, k+1);
+                    # acc_len crosses to the host HERE, once per round, as
+                    # one (S,) array — never a per-token sync inside the
+                    # jitted round
+                    toks = np.asarray(ys[0])
+                    lps = np.asarray(ys[1])
+                    acc_len = np.asarray(ys[3])
+                    next_t1 = np.array(ys[4])   # writable: admits reset
+                    pulled = [toks, lps, acc_len, next_t1]
+                else:
+                    toks = np.asarray(ys[0]).T                # (S, chunk)
+                    lps = np.asarray(ys[1]).T
+                    acc_len = None
+                    pulled = [toks, lps]
                 tr = (np.asarray(ys[2]) if self.collect_router_trace
                       else None)
-                acc_len = np.asarray(ys[3])
-                next_t1 = np.array(ys[4])   # writable: admits reset entries
-            else:
-                toks = np.asarray(ys[0]).T                   # (S, chunk)
-                lps = np.asarray(ys[1]).T
-                tr = (np.asarray(ys[2]) if self.collect_router_trace
-                      else None)
-                acc_len = None
-            uid_map = sched.uid_by_slot()
-            live_mask = sched.active_mask()
-            now = time.perf_counter() - t0
-            # per-step times interpolate from the chunk's decode start, so
-            # first-token stamps land on their step instead of quantizing
-            # to the chunk boundary
-            accepted = sched.record_chunk(toks, lps, tr, now,
-                                          t_start=td - t0,
-                                          valid_len=acc_len)  # (chunk, S)
-            generated += int(accepted.sum())
-            if spec_on:
-                live_after = sched.uid_by_slot()
-                for i in uid_map:
-                    spec_drafted += spec_k
-                    spec_acc += int(acc_len[i]) - 1
-                    # toks[i, 0] (the round's t1) was observed when it
-                    # was sampled — at admission or as the previous
-                    # round's bonus token — so only the accepted draft
-                    # suffix is new to the drafter here
-                    n_new = int(accepted[:, i].sum())
-                    if n_new > 1:
-                        drafter.observe(i, toks[i, 1:n_new])
-                    if live_after.get(i) == uid_map[i]:
-                        # slot survives the round: the bonus token it
-                        # will commit next round conditions proposals now
-                        drafter.observe(i, np.asarray([next_t1[i]]))
+                if tr is not None:
+                    pulled.append(tr)
+                tl.count("pulled_bytes", sum(a.nbytes for a in pulled))
+
+            with tl.span("record"):
+                chunks += 1
+                uid_map = sched.uid_by_slot()
+                live_mask = sched.active_mask()
+                tl.count("live_slot_steps",
+                         int(live_mask.sum()) * toks.shape[1])
+                now = time.perf_counter() - t0
+                # per-step times interpolate from the chunk's decode
+                # start, so first-token stamps land on their step instead
+                # of quantizing to the chunk boundary
+                accepted = sched.record_chunk(toks, lps, tr, now,
+                                              t_start=td - t0,
+                                              valid_len=acc_len)
+                generated += int(accepted.sum())
+                if spec_on:
+                    live_after = sched.uid_by_slot()
+                    for i in uid_map:
+                        spec_drafted += spec_k
+                        spec_acc += int(acc_len[i]) - 1
+                        # toks[i, 0] (the round's t1) was observed when
+                        # it was sampled — at admission or as the
+                        # previous round's bonus token — so only the
+                        # accepted draft suffix is new to the drafter
+                        n_new = int(accepted[:, i].sum())
+                        if n_new > 1:
+                            drafter.observe(i, toks[i, 1:n_new])
+                        if live_after.get(i) == uid_map[i]:
+                            # slot survives the round: the bonus token it
+                            # will commit next round conditions proposals
+                            drafter.observe(i, np.asarray([next_t1[i]]))
+                if tr is not None:
+                    masked = np.where(accepted[:, None, :, None], tr,
+                                      -1).astype(tr.dtype)
+                    traces.append(masked)
             if paged:
-                live = sched.uid_by_slot()
-                for slot_i, uid in uid_map.items():
-                    if live.get(slot_i) != uid:   # retired this chunk
-                        pool.release(slot_pages.pop(slot_i))
-                        # unmap before the next chunk decodes: the freed
-                        # pages may be re-allocated, and a dead slot keeps
-                        # scan-stepping (its writes must hit the trash
-                        # page, not the new tenant)
-                        caches = self._reset_paged(caches,
-                                                   jnp.int32(slot_i))
-            if tr is not None:
-                masked = np.where(accepted[:, None, :, None], tr,
-                                  -1).astype(tr.dtype)
-                traces.append(masked)
-                if self._stores:
+                with tl.span("page_reset"):
+                    live = sched.uid_by_slot()
+                    for slot_i, uid in uid_map.items():
+                        if live.get(slot_i) != uid:   # retired this chunk
+                            pool.release(slot_pages.pop(slot_i))
+                            # unmap before the next chunk decodes: the
+                            # freed pages may be re-allocated, and a dead
+                            # slot keeps scan-stepping (its writes must
+                            # hit the trash page, not the new tenant)
+                            caches = self._reset_paged(caches,
+                                                       jnp.int32(slot_i))
+            if tr is not None and self._stores:
+                with tl.span("metering"):
                     before = sum(s.total_bytes for s in self._stores)
                     shard_before = self._shard_totals()
                     if spec_on:
@@ -1279,16 +1321,19 @@ class ServeEngine:
                             prefetcher=self._prefetcher)
                     metered_tokens += ntok
                     sched.add_slot_bytes(slot_bytes, uid_map)
-                    if self._stream is not None:
+                if self._stream is not None:
+                    with tl.span("controller"):
                         # staged copies the accepted routing never
                         # touched become wasted prefetch THIS chunk, so
                         # the controller's `moved` sees every byte the
                         # chunk put on the link
                         self._stream.flush_unclaimed()
-                    if self._controller is not None:
-                        # chunk boundary: the chunk's wire bytes (demand +
-                        # compensator + prefetch) close the control loop;
-                        # per-shard deltas feed the per_shard budget scope
+                if self._controller is not None:
+                    with tl.span("controller"):
+                        # chunk boundary: the chunk's wire bytes (demand
+                        # + compensator + prefetch) close the control
+                        # loop; per-shard deltas feed the per_shard
+                        # budget scope
                         moved = sum(s.total_bytes
                                     for s in self._stores) - before
                         self._controller.update(
@@ -1321,8 +1366,10 @@ class ServeEngine:
             }
         by_uid = {res.uid: res for res in sched.finished}
         results = [by_uid[u] for u in order]
-        return ServeStats(results, num_slots, chunk, total_s, prefill_s,
-                          decode_s, chunks, generated,
+        return ServeStats(results, num_slots, chunk, total_s,
+                          tl.total_s("prefill", "claim"),
+                          tl.total_s("decode.dispatch", "decode.sync"),
+                          chunks, generated, timeline=tl,
                           cache_hbm_bytes=cache_hbm,
                           prefill_tokens=prefill_tok,
                           page_report=(pool.report() if pool is not None
