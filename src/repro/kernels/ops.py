@@ -190,9 +190,13 @@ def fused_expert_matmul(xe: jax.Array, stack, me: jax.Array, *,
     bitplane unpack + HQQ dequant at each expert's TRUE width
     (``stack.expert_bits``), the rank-capped compensator GEMM, and the
     gate weighting — accumulated in f32 VMEM scratch, no HBM
-    round-trips.  Block sizes come from ``kernels.autotune`` unless
-    pinned by the caller; the traced ``rank_cap``/``gates`` enter as
-    data, so controller plan changes never recompile.
+    round-trips.  The rank-space activation ``(xe * me) @ U`` is built
+    once per (expert, token tile), on the first output tile, and reused
+    by the others, so each expert's U is read once per token tile and
+    its V once per output tile.  Block sizes come from
+    ``kernels.autotune`` unless pinned by the caller; the traced
+    ``rank_cap``/``gates`` enter as data, so controller plan changes
+    never recompile.
     """
     out_dtype = out_dtype or xe.dtype
     impl = _pick(impl)

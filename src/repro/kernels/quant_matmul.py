@@ -12,8 +12,11 @@ An optional fused epilogue adds the router-guided low-rank compensation
 never round-trips through HBM.
 
 Grid: (M/bm, N/bn, K/bk) with a VMEM f32 accumulator; K is the innermost
-(sequential) dimension.  Constraints: bk % PACK_BLOCK == 0 (block-local
-packing), bk % group_size == 0 (whole quant groups per tile).
+(sequential) dimension.  The fused expert-stack kernel adds a leading
+expert dimension, (E, M/bm, N/bn, K/bk), and walks N in order too: its
+rank-space activation is built on the first N tile and reused by the
+rest.  Constraints: bk % PACK_BLOCK == 0 (block-local packing),
+bk % group_size == 0 (whole quant groups per tile).
 """
 from __future__ import annotations
 
@@ -173,9 +176,10 @@ def _fused_kernel(bits, group_size, n_k, pad_rank, has_gates,
     """One grid step of the fused decode kernel (see fused_expert_matmul).
 
     Grid (e, i, j, kk): expert e, token tile i, output tile j, K step kk
-    (innermost, sequential).  ``cap_ref`` (1,) / ``eb_ref`` (E,) are the
-    scalar-prefetched rank cap and TRUE per-expert widths (SMEM).  refs
-    layout:
+    (innermost, sequential); j runs in order too, since the rank-space
+    activation built at j == 0 carries over to the later output tiles.
+    ``cap_ref`` (1,) / ``eb_ref`` (E,) are the scalar-prefetched rank cap
+    and TRUE per-expert widths (SMEM).  refs layout:
       [planes..., scale, zero, u, u_scale, v, v_scale, me, (ge,)]
       + [out] + [acc, xu_acc scratch]
     Everything accumulates in f32 VMEM scratch; only the finished
@@ -194,11 +198,15 @@ def _fused_kernel(bits, group_size, n_k, pad_rank, has_gates,
     out_ref, acc_ref, xu_ref = refs[pos], refs[pos + 1], refs[pos + 2]
 
     e = pl.program_id(0)
+    j = pl.program_id(2)
     kk = pl.program_id(3)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((j == 0) & (kk == 0))
+    def _init_xu():
         xu_ref[...] = jnp.zeros_like(xu_ref)
 
     # -- dequant at this expert's TRUE width: planes at or above
@@ -213,11 +221,14 @@ def _fused_kernel(bits, group_size, n_k, pad_rank, has_gates,
     acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     # -- rank-space compensator activation: (x * me) @ (U * u_scale),
-    # accumulated over K alongside the main matmul (j-invariant; cheap
-    # rank-R duplicate work per j tile beats an HBM round-trip for xu)
-    xm = x * me_ref[0]                                     # (bm, 1) mask
-    ud = u_ref[0].astype(jnp.float32) * us_ref[0]          # (bk, R)
-    xu_ref[...] += jnp.dot(xm, ud, preferred_element_type=jnp.float32)
+    # accumulated over K alongside the main matmul.  It does not depend
+    # on the output tile: built on the first one (j == 0), kept in
+    # scratch for the others
+    @pl.when(j == 0)
+    def _xu():
+        xm = x * me_ref[0]                                 # (bm, 1) mask
+        ud = u_ref[0].astype(jnp.float32) * us_ref[0]      # (bk, R)
+        xu_ref[...] += jnp.dot(xm, ud, preferred_element_type=jnp.float32)
 
     @pl.when(kk == n_k - 1)
     def _done():
@@ -266,9 +277,11 @@ def fused_expert_matmul_pallas(xe: jax.Array, planes: Tuple[jax.Array, ...],
     TRUE per-expert widths.  The last two ride scalar prefetch (SMEM).
 
     The f32 accumulator and the (bm, R) rank-space activation live in
-    VMEM scratch for the whole K walk — no intermediate (dequantized
-    weight, compensator product, or pre-gate output) ever round-trips
-    to HBM.
+    VMEM scratch — no intermediate (dequantized weight, compensator
+    product, or pre-gate output) ever round-trips to HBM.  The
+    activation is accumulated over K on the first N tile of each
+    (expert, token tile) and reused by the others, so U is read once
+    per token tile; the N dimension therefore runs in order.
     """
     e, m, k = xe.shape
     n = scale.shape[-1]
@@ -289,7 +302,10 @@ def fused_expert_matmul_pallas(xe: jax.Array, planes: Tuple[jax.Array, ...],
                  for p, _ in PLANES[bits]]
     in_specs += [spec((1, bk // group_size, bn),
                       lambda e, i, j, kk: (e, kk, j))] * 2
-    in_specs += [spec((1, bk, r), lambda e, i, j, kk: (e, kk, 0)),
+    # U is only read on the first N tile: afterwards its block index
+    # holds at the last K block, so the pipeline fetches nothing more
+    in_specs += [spec((1, bk, r), lambda e, i, j, kk:
+                      (e, jnp.where(j == 0, kk, n_k - 1), 0)),
                  spec((1, 1, r), lambda e, i, j, kk: (e, 0, 0)),
                  spec((1, r, bn), lambda e, i, j, kk: (e, 0, j)),
                  spec((1, r, 1), lambda e, i, j, kk: (e, 0, 0)),
@@ -312,7 +328,7 @@ def fused_expert_matmul_pallas(xe: jax.Array, planes: Tuple[jax.Array, ...],
                             pltpu.VMEM((bm, r), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
+            dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
         name=f"fused_expert_b{bits}" + ("_gated" if has_gates else ""),

@@ -220,15 +220,16 @@ def fused_hbm_bytes(e: int, m: int, k: int, n: int, bits: int,
     """Analytic HBM traffic of one fused-kernel invocation (per expert
     stack), tile-multiplicity aware.
 
-    The grid is (E, m/bm, n/bn, k/bk) with K innermost-sequential;
-    every operand block is fetched once per grid step that maps to it
-    (conservative: Mosaic elides refetches of blocks whose index map is
-    constant across consecutive steps, so this is an upper bound):
+    The grid is (E, m/bm, n/bn, k/bk) with N and K sequential; every
+    operand block is fetched once per grid step that maps to it, except
+    where its index map is constant across consecutive steps (Mosaic
+    elides those refetches; the rest of this count is an upper bound):
 
     - x:        (bm, bk) per (i, j, kk)   -> m*k*4      x  n/bn
     - planes:   packed bits per (j, kk)   -> packed(k,n) x  m/bm
     - scale/zero: f32 per (j, kk)         -> 2*(k/g)*n*4 x m/bm
-    - U (int8): (bk, r) per (i, j, kk)    -> k*r        x (m/bm)(n/bn)
+    - U (int8): (bk, r) per (i, kk), only on the first N tile, whose
+      rank-space activation the later N tiles reuse -> k*r x m/bm
     - V (int8): (r, bn) per (i, j)        -> r*n        x  m/bm
     - me/gates: (bm,) per (i, j)          -> m*4        x  n/bn (x2)
     - out:      written once              -> m*n*4
@@ -244,7 +245,7 @@ def fused_hbm_bytes(e: int, m: int, k: int, n: int, bits: int,
     planes_b = packed_nbytes(bits, k, n)
     scales_b = 2 * (k // group_size) * n * 4
     x_b = m * k * 4 * nj
-    u_b = k * rank * mi * nj
+    u_b = k * rank * mi
     v_b = rank * n * mi
     f_scales_b = rank * 4 * 2 * mi * nj
     masks_b = 2 * m * 4 * nj

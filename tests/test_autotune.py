@@ -8,7 +8,7 @@ import numpy as np
 from repro.core.quantize import PACK_BLOCK
 from repro.kernels import autotune
 from repro.kernels.autotune import Autotuner, clamp_tiles, choose_tiles
-from repro.launch.roofline import (fused_tile_candidates,
+from repro.launch.roofline import (fused_hbm_bytes, fused_tile_candidates,
                                    fused_tile_vmem_bytes)
 
 
@@ -48,6 +48,25 @@ def test_roofline_candidates_fit_vmem_and_problem():
                 <= VMEM_BYTES * VMEM_BUDGET)
     # best-first: the first candidate has the largest K tile
     assert cands[0][2] == max(c[2] for c in cands)
+
+
+def test_fused_hbm_bytes_reads_u_once_per_token_tile():
+    """Mixtral-8x7B decode w1 (8 experts, 16 slots, K 4096, N 14336,
+    2-bit codes in groups of 64, rank 256) at the decode tiles (16, 256,
+    512): U is read once per token tile, 8 MiB, not once per N tile
+    (56 times that), so the call reads about 340 MB."""
+    e, m, k, n, r = 8, 16, 4096, 14336, 256
+    tiles = choose_tiles("fused", bits=2, group_size=64, rank=r,
+                         m=m, k=k, n=n)
+    assert tiles == (16, 256, 512)
+    total = fused_hbm_bytes(e, m, k, n, 2, 64, r, *tiles)
+    assert total == 339_664_896
+    # one more token tile reads U once more, one more N tile does not
+    # read it again
+    u = e * k * r
+    assert fused_hbm_bytes(e, 2 * m, k, n, 2, 64, r, *tiles) - total > u
+    more_n = fused_hbm_bytes(e, m, k, n + 256, 2, 64, r, *tiles) - total
+    assert more_n < u
 
 
 def test_record_and_disk_roundtrip(tmp_path, monkeypatch):

@@ -6,7 +6,8 @@ gate-weighted combine), executed by the Pallas interpreter on CPU, must
 bit-match the pure-jnp oracle across
 
     bits x rank_cap {0, half, full} x comp-mask {none, partial, all}
-    x gates {absent, present} x heterogeneous expert_bits,
+    x gates {absent, present} x heterogeneous expert_bits, and at
+    pinned tiles that give several token, N and K tiles per expert,
 
 and the traced (top_n, rank_cap) plan row must never trigger a
 recompile (the compile-count pin).  A compiled-Mosaic parity cell runs
@@ -82,6 +83,28 @@ def test_fused_parity_topn_x_gates(mask_mode, gated):
     stack, _ = _stack(bits=2)
     xe, me, ge = _inputs(4, 8, 128, mask_mode, gated, seed=7)
     _parity(stack, xe, me, ge, jnp.int32(stack.pad_rank // 2))
+
+
+@pytest.mark.parametrize("mask_mode", ["partial", "all"])
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("rank_mode", ["full", "partial", "zero"])
+def test_fused_parity_multi_tile(rank_mode, gated, mask_mode):
+    """Several token, N and K tiles per expert (5 x 4 x 4 with pinned
+    tiles): the rank-space activation is built on the first N tile of
+    each (expert, token tile) and reused by the later ones, so an
+    activation left stale from another expert or token tile, or never
+    built for a later N tile, shows here (one N tile cannot show it)."""
+    stack, _ = _stack(bits=2, e=3, k=512, n=512, rank_budget=16, seed=31)
+    xe, me, ge = _inputs(3, 40, 512, mask_mode, gated, seed=37)
+    cap = {"full": None, "partial": jnp.int32(stack.pad_rank // 2),
+           "zero": jnp.int32(0)}[rank_mode]
+    y_ref = ops.fused_expert_matmul(xe, stack, me, gates=ge, rank_cap=cap,
+                                    impl="ref", out_dtype=jnp.float32)
+    y_pl = ops.fused_expert_matmul(xe, stack, me, gates=ge, rank_cap=cap,
+                                   impl="pallas_interpret",
+                                   out_dtype=jnp.float32,
+                                   bm=8, bn=128, bk=128)
+    np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_ref), **TOL)
 
 
 def test_fused_parity_heterogeneous_expert_bits():
